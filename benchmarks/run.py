@@ -27,6 +27,7 @@ from benchmarks import (beyond_paper, cluster_bench, dryrun_table,
                         dynamic_scenarios, fig3_heatmap, fig4_links,
                         fig5_convergence, fig6_stragglers, kernel_bench,
                         roofline_table, shard_scaling)
+from repro.launch.compile_cache import enable_compile_cache
 
 BENCHES = {
     "fig3": fig3_heatmap.main,
@@ -78,6 +79,7 @@ class _RowTee(io.TextIOBase):
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--full", action="store_true")
     ap.add_argument("--only", default=None, metavar="NAME[,NAME...]",

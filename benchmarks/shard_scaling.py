@@ -3,7 +3,9 @@
 Weak scaling sweep: mesh size m in {1, 2, 4, 8} with N = base_n * m clients,
 one child process per mesh size (the XLA host-platform device count is fixed
 at backend init, so ``XLA_FLAGS=--xla_force_host_platform_device_count=m``
-must be set before the child imports jax).  Each child times the jitted
+must be set before the child imports jax).  The children run on the CPU
+only (``JAX_PLATFORMS=cpu``): on a TPU host the parent already holds the
+chip.  Each child times the jitted
 exchange-gate scoring program and a full FL segment (stacking + donated
 rounds) with the client axis sharded per ``ShardingRules``, and checks
 parity against the unsharded single-device program in-process.
@@ -71,7 +73,10 @@ def child_main(mesh: int, n_clients: int, quick: bool, iters: int) -> None:
 
 
 def _spawn(mesh: int, n_clients: int, quick: bool, iters: int) -> dict:
-    env = dict(os.environ)
+    # Children run on forced host devices, on the CPU only: they are spawned
+    # after the parent's other benches touched JAX, so a parent on a chip
+    # holds it and a child that asked for it would fail or hang.
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
     env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") +
                         f" --xla_force_host_platform_device_count={mesh}")
     cmd = [sys.executable, "-m", "benchmarks.shard_scaling", "--child",

@@ -25,6 +25,7 @@ from repro.core import qlearning as ql
 from repro.core import rewards as rw
 from repro.core import trust as tr
 from repro.data.tokens import make_client_token_data
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.registry import build_model, make_train_step
 
 N_CLIENTS = 6
@@ -97,6 +98,7 @@ def eval_ppl(model, params, key, vocab):
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="llama3.2-1b", choices=list(ARCH_IDS))
     ap.add_argument("--steps", type=int, default=30)

@@ -14,10 +14,12 @@ from repro.core.qlearning import RLConfig
 from repro.data import partition_by_classes
 from repro.data.synthetic import fmnist_like_split
 from repro.fl import FLConfig, fl_train, linear_evaluation
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.autoencoder import AEConfig
 
 
 def main():
+    enable_compile_cache()
     key = jax.random.PRNGKey(0)
     ae_cfg = AEConfig(28, 28, 1, widths=(8, 16), latent_dim=32)
 

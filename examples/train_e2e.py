@@ -16,11 +16,13 @@ from repro import optim
 from repro.checkpoint import save_pytree
 from repro.configs import TrainConfig, get_config
 from repro.data.tokens import topic_token_batch
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.models.registry import build_model, make_train_step
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=200)
     ap.add_argument("--batch", type=int, default=4)

@@ -394,14 +394,15 @@ def _gate_scores(params, own, own_mask, cand, cand_mask, allowed, fail_u,
     k, r = cand.shape[1:3]
 
     recon = jax.vmap(lambda p, x: ae.reconstruct(p, x, ae_cfg))
+    gate_score = sh.client_map(ops.recon_gate_score, rules)
     y_own = recon(params, own)
-    base = ops.recon_gate_score(y_own.reshape(n, max_n, -1),
-                                own.reshape(n, max_n, -1), own_mask)
+    base = gate_score(y_own.reshape(n, max_n, -1), own.reshape(n, max_n, -1),
+                      own_mask)
 
     cand_flat = cand.reshape((n, k * r) + cand.shape[3:])
     y_cand = recon(params, cand_flat)
-    scores = ops.recon_gate_score(y_cand.reshape(n, k, r, -1),
-                                  cand.reshape(n, k, r, -1), cand_mask)
+    scores = gate_score(y_cand.reshape(n, k, r, -1),
+                        cand.reshape(n, k, r, -1), cand_mask)
 
     if apply_channel:
         fail = fail_u < p_fail[jnp.arange(n), in_edge]

@@ -25,6 +25,7 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
+from repro import sharding as sh
 from repro.kernels import ops as kops
 
 
@@ -145,7 +146,8 @@ def kmeans_masked(key, x, size, k: int, n_iters: int = 25) -> KMeansResult:
     return KMeansResult(cents, assign, inertia)
 
 
-def kmeans_batched(key, x, sizes, k: int, n_iters: int = 25) -> KMeansResult:
+def kmeans_batched(key, x, sizes, k: int, n_iters: int = 25,
+                   rules=None) -> KMeansResult:
     """All clients' K-means++ fits in one vmapped program.
 
     x: (N, cap, d) padded client stack; sizes: (N,).  Returns a stacked
@@ -154,12 +156,12 @@ def kmeans_batched(key, x, sizes, k: int, n_iters: int = 25) -> KMeansResult:
     ``jax.random.split(key, N)`` convention of the list path, and the
     assignment hot spot still routes through ``ops.kmeans_assign`` (the
     Pallas kernel batches over the grid under vmap).  Entirely row-local:
-    on a CLIENTS mesh every client's fit stays on its shard with zero
+    with ``rules`` every shard fits its own clients
+    (``sharding.client_map``, which the Pallas kernel needs) with zero
     collectives."""
     keys = jax.random.split(key, x.shape[0])
-    return jax.vmap(
-        lambda kk, xx, ss: kmeans_masked(kk, xx, ss, k, n_iters)
-    )(keys, x, sizes)
+    fit = jax.vmap(lambda kk, xx, ss: kmeans_masked(kk, xx, ss, k, n_iters))
+    return sh.client_map(fit, rules)(keys, x, sizes)
 
 
 def wcss_elbow(key, x, k_candidates) -> int:

@@ -109,7 +109,7 @@ def _cluster_impl(key, data, sizes, n_pca, n_clusters, kmeans_iters, rules):
         rules)
     pca = pca_lib.fit_pca_federated_stacked(flats, mask, n_pca, rules)
     z = sh.constrain_clients(pca.transform(flats), rules)
-    res = km.kmeans_batched(key, z, sizes, n_clusters, kmeans_iters)
+    res = km.kmeans_batched(key, z, sizes, n_clusters, kmeans_iters, rules)
     return pca, sh.constrain_clients(res.centroids, rules), \
         sh.constrain_clients(res.assignments, rules)
 
@@ -147,17 +147,22 @@ def cluster_clients_loop(key, datasets, cfg: PipelineConfig):
     the vmapped program must match it bit-for-bit at mesh=1).
 
     The PCA moments are looped per client and folded exactly like the
-    stacked path; the basis *projection* ``pca.transform`` is one shared
-    batched call in both paths, because XLA:CPU's gemm reduction order is
-    not batch-layout-invariant — a per-client (cap, d) @ (d, k) projection
-    lands ~1e-6 off the batched one, which would smear an arbitrary bit
-    difference over everything downstream without testing any of the
-    masking machinery this reference exists to pin down."""
+    stacked path, each client's moments one jitted call: op-by-op dispatch
+    materialises ``xm.T`` before the gemm, and XLA:CPU then sums the second
+    moment in another order (~1e-6 off) than the fused ``xm.T @ xm`` the
+    vmapped program and a jitted per-client call both compile to.  The
+    basis *projection* ``pca.transform`` is one shared batched call in both
+    paths, because XLA:CPU's gemm reduction order is not
+    batch-layout-invariant — a per-client (cap, d) @ (d, k) projection
+    lands ~1e-6 off the batched one.  Either difference would smear an
+    arbitrary bit difference over everything downstream without testing any
+    of the masking machinery this reference exists to pin down."""
     cd = as_client_data(datasets)
     n, cap = cd.n_clients, cd.cap
     flats = cd.data.reshape(n, cap, -1)
     mask = cd.mask(flats.dtype)
-    moments = [pca_lib.client_moments(flats[i], mask[i]) for i in range(n)]
+    moments_fn = jax.jit(pca_lib.client_moments)
+    moments = [moments_fn(flats[i], mask[i]) for i in range(n)]
     s1 = jnp.sum(jnp.stack([m[0] for m in moments]), axis=0)
     s2 = jnp.sum(jnp.stack([m[1] for m in moments]), axis=0)
     pca = pca_lib._pca_from_moments(s1, s2, jnp.sum(mask), cfg.n_pca)

@@ -70,6 +70,7 @@ from repro import obs
 from repro import sharding as sh
 from repro.core import exchange as ex
 from repro.core import qlearning as ql
+from repro.core.batching import ClientData
 from repro.core.channel import failure_prob
 from repro.core.pipeline import (PipelineConfig, _cluster_impl,
                                  cluster_clients, link_rewards, run_pipeline,
@@ -262,8 +263,9 @@ def _orchestrate(key, datasets, labels, ae_cfg, cfg: OrchestratorConfig,
         exch = None
         start_segment = rs.segment + 1
     else:
-        n = len(datasets) if isinstance(datasets, (list, tuple)) else \
-            datasets.n_clients
+        # ClientData is a NamedTuple: test for it before the list form
+        n = datasets.n_clients if isinstance(datasets, ClientData) else \
+            len(datasets)
         # The environment owns the channel; seeding it with the pipeline's
         # channel sub-key makes segment 0's RSS the one-shot draw
         # bit-for-bit.  (The fault plane leaves segment 0 untouched by

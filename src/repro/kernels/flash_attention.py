@@ -25,17 +25,6 @@ BLOCK_Q = 512
 BLOCK_K = 512
 
 
-def _compiler_params_cls():
-    # renamed TPUCompilerParams -> CompilerParams across jax releases
-    cls = getattr(pltpu, "CompilerParams",
-                  getattr(pltpu, "TPUCompilerParams", None))
-    if cls is None:
-        raise AttributeError(
-            "jax.experimental.pallas.tpu exposes neither CompilerParams nor "
-            "TPUCompilerParams; unsupported jax version")
-    return cls
-
-
 def _make_kernel(*, scale, causal, window, q_offset, block_q, block_k,
                  n_kv_blocks):
     def kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr):
@@ -127,7 +116,7 @@ def flash_attention_pallas(q, k, v, *, causal=True, window=None, q_offset=0,
             pltpu.VMEM((block_q,), jnp.float32),
             pltpu.VMEM((block_q, hd), jnp.float32),
         ],
-        compiler_params=_compiler_params_cls()(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(qf, kf, vf)

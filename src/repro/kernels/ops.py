@@ -6,8 +6,8 @@ Dispatch policy:
   * CPU otherwise         -> pure-jnp oracle (`ref.py`)
 
 Wrappers also handle padding to kernel-friendly shapes (d -> x128 for the
-MXU, sequence -> block multiples) and un-padding of the results, so callers
-never see alignment constraints.
+MXU, sequence -> block multiples), the choice of block sizes, and
+un-padding of the results, so callers never see alignment constraints.
 """
 from __future__ import annotations
 
@@ -18,8 +18,9 @@ import jax.numpy as jnp
 
 from repro.kernels import ref
 from repro.kernels.flash_attention import flash_attention_pallas
-from repro.kernels.kmeans_assign import kmeans_assign_pallas
-from repro.kernels.recon_gate import recon_gate_pallas
+from repro.kernels.kmeans_assign import BLOCK_N, kmeans_assign_pallas
+from repro.kernels.recon_gate import (BLOCK_BYTES, GROUP_BLOCK,
+                                      recon_gate_pallas)
 
 
 def _use_pallas(override):
@@ -32,6 +33,10 @@ def _use_pallas(override):
 
 def _interpret() -> bool:
     return jax.default_backend() != "tpu"
+
+
+def _round_up(n: int, mult: int) -> int:
+    return -(-n // mult) * mult
 
 
 def _pad_to(x, axis, mult, value=0.0):
@@ -52,23 +57,23 @@ def kmeans_assign(x, centroids, use_pallas=None):
     """x: (n, d), centroids: (k, d) -> (assign (n,) int32, min_d2 (n,) f32)."""
     if not _use_pallas(use_pallas):
         return ref.kmeans_assign_ref(x, centroids)
-    n, d = x.shape
-    block_n = min(512, max(8, 1 << (n - 1).bit_length()))
-    xp, pad_n = _pad_to(x, 0, block_n)
+    n = x.shape[0]
+    x = x.astype(jnp.float32)
+    c = centroids.astype(jnp.float32)
+    # squared norms with the oracle's expressions, before any padding
+    x2 = jnp.sum(jnp.square(x), axis=1)[None, :]              # (1, n)
+    c2 = jnp.sum(jnp.square(c), axis=1)[:, None]              # (k, 1)
+    block_n = min(BLOCK_N, _round_up(n, 128))
+    xp, _ = _pad_to(x, 0, block_n)
     xp, _ = _pad_to(xp, 1, 128)
-    cp, _ = _pad_to(centroids, 1, 128)
-    # pad k to a multiple of 8; padded centroids at +inf distance
-    k = centroids.shape[0]
-    pad_k = (-k) % 8
-    if pad_k:
-        big = jnp.full((pad_k, cp.shape[1]), 1e15, cp.dtype)
-        cp = jnp.concatenate([cp, big], axis=0)
-    assign, min_d2 = kmeans_assign_pallas(
-        xp, cp, interpret=_interpret(),
-        block_n=min(block_n, xp.shape[0]))
-    if pad_n:
-        assign, min_d2 = assign[:n], min_d2[:n]
-    return assign, min_d2
+    x2, _ = _pad_to(x2, 1, block_n)
+    cp, _ = _pad_to(c, 1, 128)
+    cp, _ = _pad_to(cp, 0, 8)
+    c2, _ = _pad_to(c2, 0, 8, value=1e30)   # padding centroids never win
+    assign, min_d2 = kmeans_assign_pallas(xp, cp, x2, c2,
+                                          interpret=_interpret(),
+                                          block_n=block_n)
+    return assign[0, :n], min_d2[0, :n]
 
 
 # ---------------------------------------------------------------------------
@@ -88,16 +93,37 @@ def recon_gate_score(y, x, mask, use_pallas=None):
     g = 1
     for s in lead:
         g *= s
-    yf = y.reshape(g, r, p)
-    xf = x.reshape(g, r, p)
-    mf = mask.reshape(g, r)
-    yf, _ = _pad_to(yf, 2, 128)
-    xf, _ = _pad_to(xf, 2, 128)
-    yf, _ = _pad_to(yf, 1, 8)
-    xf, _ = _pad_to(xf, 1, 8)
-    mf, _ = _pad_to(mf, 1, 8)   # padded samples carry mask 0: never counted
-    out = recon_gate_pallas(yf, xf, mf, p_true=p, interpret=_interpret())
-    return out.reshape(lead)
+    block_r, block_p = _gate_blocks(r, p)
+    # zero padding: equal in y and x (zero residual), mask 0 on padded
+    # samples and groups, so padding never moves a score
+    padded = []
+    for a, shape, mults in (
+            (y, (g, r, p), (GROUP_BLOCK, block_r, 128)),
+            (x, (g, r, p), (GROUP_BLOCK, block_r, 128)),
+            (mask, (g, r), (GROUP_BLOCK, block_r))):
+        a = a.astype(jnp.float32).reshape(shape)
+        for axis, mult in enumerate(mults):
+            a, _ = _pad_to(a, axis, mult)
+        padded.append(a)
+    out = recon_gate_pallas(*padded, p_true=p, block_r=block_r,
+                            block_p=block_p, interpret=_interpret())
+    return out[:g, 0].reshape(lead)
+
+
+def _gate_blocks(r: int, p: int) -> tuple[int, int]:
+    """(block_r, block_p) for the gate kernel: the whole (8-padded) sample
+    axis in one block when a 128-pixel tile of it fits ``BLOCK_BYTES``,
+    else 128-multiple sample blocks; then the widest 128-multiple pixel
+    block that divides the padded P and keeps the tile within budget."""
+    row_bytes = GROUP_BLOCK * 128 * 4        # one sample's 128-pixel slab
+    block_r = _round_up(r, 8)
+    if block_r * row_bytes > BLOCK_BYTES:
+        block_r = BLOCK_BYTES // row_bytes // 128 * 128
+    p_tiles = _round_up(p, 128) // 128
+    fit = max(1, BLOCK_BYTES // (block_r * row_bytes))
+    block_p = 128 * max(t for t in range(1, p_tiles + 1)
+                        if p_tiles % t == 0 and t <= fit)
+    return block_r, block_p
 
 
 # ---------------------------------------------------------------------------

@@ -17,7 +17,10 @@ def kmeans_assign_ref(x, centroids):
     c = centroids.astype(jnp.float32)
     x2 = jnp.sum(jnp.square(x), axis=1, keepdims=True)        # (n, 1)
     c2 = jnp.sum(jnp.square(c), axis=1)                        # (k,)
-    d2 = x2 - 2.0 * (x @ c.T) + c2[None, :]                    # (n, k)
+    # full f32: a TPU's default matmul precision rounds f32 operands to
+    # bf16, far coarser than the kernel's cross term
+    cross = jnp.matmul(x, c.T, precision=jax.lax.Precision.HIGHEST)
+    d2 = x2 - 2.0 * cross + c2[None, :]                        # (n, k)
     assign = jnp.argmin(d2, axis=1).astype(jnp.int32)
     min_d2 = jnp.maximum(jnp.min(d2, axis=1), 0.0)
     return assign, min_d2

@@ -33,6 +33,7 @@ from repro.core.pipeline import (PipelineConfig, cluster_clients,
                                  cluster_clients_loop)
 from repro.core.qlearning import uniform_graph
 from repro.fl.trainer import FLConfig, fl_train
+from repro.launch.mesh import make_client_mesh
 from repro.models.autoencoder import AEConfig
 
 
@@ -62,8 +63,7 @@ def make_rules(mesh_size: int | None) -> sh.ShardingRules | None:
     """ShardingRules over a (data=mesh_size,) mesh; ``None`` -> unsharded."""
     if mesh_size is None:
         return None
-    mesh = jax.make_mesh((mesh_size,), ("data",))
-    return sh.ShardingRules.default(mesh)
+    return sh.ShardingRules.default(make_client_mesh(mesh_size))
 
 
 def build_world(cfg: LabConfig) -> dict:

@@ -145,11 +145,11 @@ def logical_to_sharding(tree_logical, tree_shapes, rules: ShardingRules):
 
 
 def constrain(x, rules: ShardingRules, logical: Sequence[str | None]):
-    """with_sharding_constraint by logical axes (no-op outside a mesh)."""
-    try:
-        return jax.lax.with_sharding_constraint(x, rules.named(logical, x.shape))
-    except (ValueError, RuntimeError):
-        return x
+    """with_sharding_constraint by logical axes.
+
+    A placement the mesh cannot honour raises: a silently unconstrained
+    array would leave the client stack on one device."""
+    return jax.lax.with_sharding_constraint(x, rules.named(logical, x.shape))
 
 
 # ---------------------------------------------------------------------------
@@ -186,6 +186,30 @@ def constrain_clients(tree, rules: ShardingRules | None):
         return tree
     return jax.tree.map(
         lambda x: constrain(x, rules, client_axes(x.ndim)), tree)
+
+
+def client_map(fn, rules: ShardingRules | None):
+    """``fn`` run shard-locally over the leading client axis of its args.
+
+    Pallas (Mosaic) kernels cannot be partitioned automatically, so a
+    client-stacked program that calls one under ``rules`` maps the call
+    with ``jax.shard_map``: each device runs ``fn`` on its own clients and
+    the outputs keep the leading client axis.  Every argument (a pytree of
+    arrays) and every output must lead with the same client axis; a count
+    that does not divide the mesh is replicated, as in
+    :meth:`ShardingRules.spec`.  ``fn`` must be row-local (no cross-client
+    reduction).  ``rules=None`` returns ``fn`` itself."""
+    if rules is None:
+        return fn
+
+    def mapped(*args):
+        in_specs = jax.tree.map(
+            lambda a: rules.spec(client_axes(a.ndim), a.shape), args)
+        lead = jax.tree.leaves(in_specs, is_leaf=lambda s: isinstance(s, P))
+        return jax.shard_map(fn, mesh=rules.mesh, in_specs=in_specs,
+                             out_specs=P(lead[0][0]), check_vma=False)(*args)
+
+    return mapped
 
 
 def client_sum(x, rules: ShardingRules | None):

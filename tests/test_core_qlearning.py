@@ -7,6 +7,7 @@ from hypothesis import given, settings, strategies as st
 
 from repro import sharding as sh
 from repro.core import qlearning as QL
+from repro.launch.mesh import make_client_mesh
 
 
 def test_policy_probs_simplex_and_no_self():
@@ -83,7 +84,7 @@ def _world(n=8, seed=0):
 
 
 def _mesh1_rules():
-    return sh.ShardingRules.default(jax.make_mesh((1,), ("data",)))
+    return sh.ShardingRules.default(make_client_mesh(1))
 
 
 def _assert_trees_equal(a, b, what):

@@ -38,7 +38,9 @@ _TAG = "MESH_PARITY "
 
 @pytest.fixture(scope="module")
 def report():
-    env = dict(os.environ)
+    # CPU only: the parent may hold an accelerator, and the child needs
+    # forced host devices anyway
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
     env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") +
                         " --xla_force_host_platform_device_count=4")
     child = os.path.join(os.path.dirname(__file__), "mesh_parity_child.py")

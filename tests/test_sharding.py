@@ -5,22 +5,18 @@ import pytest
 from jax.sharding import PartitionSpec as P
 
 from repro import sharding as sh
+from repro.launch.mesh import make_mesh
 
 
 @pytest.fixture(scope="module")
 def mesh():
     # all CPU devices in a (1, n) data/model mesh
     n = len(jax.devices())
-    return jax.make_mesh((1, n), ("data", "model"))
+    return make_mesh((1, n), ("data", "model"))
 
 
 def _abstract_mesh(sizes, names):
-    """jax >= 0.5 takes AbstractMesh(sizes, names); 0.4.x takes the zipped
-    ((name, size), ...) shape tuple — support both."""
-    try:
-        return jax.sharding.AbstractMesh(sizes, names)
-    except TypeError:
-        return jax.sharding.AbstractMesh(tuple(zip(names, sizes)))
+    return jax.sharding.AbstractMesh(sizes, names)
 
 
 def test_basic_spec(mesh):
@@ -102,7 +98,41 @@ def test_multi_pod_rules():
     devs = np.array(jax.devices())
     if devs.size < 1:
         pytest.skip("no devices")
-    mesh = jax.make_mesh((1, 1, devs.size), ("pod", "data", "model"))
+    mesh = make_mesh((1, 1, devs.size), ("pod", "data", "model"))
     rules = sh.ShardingRules.default(mesh)
     assert rules.spec((sh.BATCH, None)) == P(("pod", "data"), None)
     assert rules.data_axes() == ("pod", "data")
+
+
+@pytest.mark.parametrize("case", ["rank_mismatch", "explicit_axes"])
+def test_constrain_raises_on_impossible_placement(case):
+    """A placement the mesh cannot honour raises instead of returning the
+    array unconstrained: a spec of the wrong rank, and a mesh whose axes
+    are Explicit (``jax.make_mesh``'s default), which in-jit constraints
+    cannot name."""
+    if case == "rank_mismatch":
+        rules = sh.ShardingRules.default(make_mesh((1,), ("data",)))
+        x, logical = jax.numpy.ones((4,)), (sh.CLIENTS, None)
+    else:
+        rules = sh.ShardingRules.default(jax.make_mesh((1,), ("data",)))
+        x, logical = jax.numpy.ones((4, 2)), (sh.CLIENTS, None)
+    with pytest.raises(ValueError):
+        jax.jit(lambda a: sh.constrain(a, rules, logical))(x)
+
+
+def test_client_map_is_shard_local_identity(mesh):
+    """``client_map`` runs a row-local fn per shard; on this host's mesh the
+    result equals the unmapped fn bit for bit, and ``rules=None`` hands
+    the fn back untouched."""
+    rules = sh.ShardingRules.default(mesh)
+
+    def fn(x, tree):
+        return x * 2.0 + tree["b"][:, :1], jax.numpy.sum(x, axis=1)
+
+    x = jax.numpy.arange(12.0).reshape(4, 3)
+    tree = {"b": jax.numpy.ones((4, 2))}
+    assert sh.client_map(fn, None) is fn
+    got = jax.jit(sh.client_map(fn, rules))(x, tree)
+    want = fn(x, tree)
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
