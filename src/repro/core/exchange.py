@@ -179,6 +179,7 @@ def pretrain_autoencoders(key, datasets, ae_cfg, cfg: ExchangeConfig):
 # previously paid a full retrace per call (the step was a closure defined
 # inside the pretrain function).  (ae_cfg, lr, rules) key the cache.
 @functools.partial(jax.jit, static_argnums=(3, 4, 5))
+@jax.named_scope("exchange")
 def _pretrain_step(p, x, m, ae_cfg, lr, rules):
     p = sh.constrain_clients(p, rules)
     x = sh.constrain_clients(x, rules)
@@ -247,6 +248,7 @@ def _select_reserves(key, assignments, n_clusters_list, r: int, sizes=None):
     return sel
 
 
+@jax.named_scope("exchange")
 def select_reserves_device(key, assignments, sizes, k_max: int, r: int):
     """On-device reserve selection: the traced counterpart of
     :func:`_select_reserves`, returning the ``_sel_tensors`` layout directly.
@@ -413,6 +415,7 @@ def _gate_scores(params, own, own_mask, cand, cand_mask, allowed, fail_u,
 
 
 @functools.partial(jax.jit, static_argnums=(0, 1, 2, 3))
+@jax.named_scope("exchange")
 def _exchange_device(ae_cfg, apply_channel, out_cap, rules, params, data,
                      sizes, labels, sel_idx, sel_mask, trust_s, fail_u,
                      p_fail, in_edge):

@@ -101,6 +101,7 @@ def split_pipeline_keys(key) -> PipelineKeys:
 # ---------------------------------------------------------------------------
 
 @functools.partial(jax.jit, static_argnums=(3, 4, 5, 6))
+@jax.named_scope("cluster")
 def _cluster_impl(key, data, sizes, n_pca, n_clusters, kmeans_iters, rules):
     n, cap = data.shape[:2]
     flats = sh.constrain_clients(data.reshape(n, cap, -1), rules)

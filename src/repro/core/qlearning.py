@@ -184,6 +184,7 @@ def discover_graph(key, local_r, p_fail, cfg: RLConfig = RLConfig(),
 # jit the cache keys on (function, avals, statics) and steady-state bursts
 # are cache hits; tests/test_obs.py pins this with the compile counter.
 @functools.partial(jax.jit, static_argnums=(4, 5, 6))
+@jax.named_scope("discover")
 def _discover_impl(key, local_r, p_fail, state, cfg, n_ep, rules):
     n = local_r.shape[0]
     m = cfg.buffer_size

@@ -482,8 +482,6 @@ def _retry_exchange(key, s, cd, assigns, trust, p_fail, ae_cfg,
             ok = (e.rx, e.tx) not in failed
             retry_q.resolve(s, e, ok, cfg.retry)
             delivered += int(ok)
-        obs.mark("retry-outcome", segment=s, offered=len(due),
-                 delivered=delivered, still_queued=len(retry_q))
         return (r_exch.client_data, jnp.sum(r_exch.moved_dev), len(due),
                 delivered)
 

@@ -109,6 +109,7 @@ def _masked_mean(tree, mask):
         tree)
 
 
+@jax.named_scope("fl")
 def _round_body(cfg: FLConfig, ae_cfg, carry, data, sizes, agg_mask,
                 keys_round, rules=None):
     """One aggregation round: ``tau_a`` scanned local iterations + a masked
@@ -210,6 +211,7 @@ _round_fn = functools.partial(jax.jit, static_argnums=(0, 1, 7),
 
 
 @functools.partial(jax.jit, static_argnums=2)
+@jax.named_scope("fl")
 def _eval_loss_fn(params, eval_data, ae_cfg):
     return ae.recon_loss(params, eval_data, ae_cfg)
 

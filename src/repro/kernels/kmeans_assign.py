@@ -77,4 +77,5 @@ def kmeans_assign_pallas(x, centroids, x2, c2, *, interpret: bool = False,
             jax.ShapeDtypeStruct((1, n), jnp.float32),
         ],
         interpret=interpret,
+        name="kmeans_assign",
     )(x, centroids, x2, c2)

@@ -53,6 +53,7 @@ def _pad_to(x, axis, mult, value=0.0):
 # kmeans assignment
 # ---------------------------------------------------------------------------
 
+@jax.named_scope("kmeans_assign")
 def kmeans_assign(x, centroids, use_pallas=None):
     """x: (n, d), centroids: (k, d) -> (assign (n,) int32, min_d2 (n,) f32)."""
     if not _use_pallas(use_pallas):
@@ -80,6 +81,7 @@ def kmeans_assign(x, centroids, use_pallas=None):
 # exchange gate: masked reconstruction-MSE scoring
 # ---------------------------------------------------------------------------
 
+@jax.named_scope("recon_gate")
 def recon_gate_score(y, x, mask, use_pallas=None):
     """y, x: (..., R, P); mask: (..., R) -> (...,) masked mean MSE.
 

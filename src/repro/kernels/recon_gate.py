@@ -97,4 +97,5 @@ def recon_gate_pallas(y, x, mask, *, p_true: int, block_r: int,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary")),
         interpret=interpret,
+        name="recon_gate",
     )(y, x, mask)

@@ -39,7 +39,7 @@ from repro.obs.tracer import (SpanEvent, drain, enabled, events,  # noqa: F401
 
 __all__ = ["span", "SpanEvent", "enable", "disable", "enabled",
            "enable_from_env", "events", "drain", "phase_totals",
-           "counters", "mark", "ManifestWriter", "read_manifest",
+           "counters", "ManifestWriter", "read_manifest",
            "maybe_profile", "profile_dir"]
 
 _writer: Optional[ManifestWriter] = None
@@ -95,10 +95,3 @@ def counters() -> dict:
     """Process-wide counter snapshot (zeros until first ``enable``)."""
     c, t, b = _counters.snapshot()
     return {"compiles": c, "transfers": t, "bytes_fetched": b}
-
-
-def mark(name: str, **fields) -> None:
-    """Write an annotation line to the active manifest (no-op without
-    one) — the bench harness marks row boundaries this way."""
-    if _writer is not None:
-        _writer.mark(name, **fields)

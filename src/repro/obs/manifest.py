@@ -77,10 +77,6 @@ class ManifestWriter:
             self._bytes += ev.bytes_fetched
         self._emit(ev.to_dict())
 
-    def mark(self, name: str, **fields) -> None:
-        """A non-span annotation line (e.g. a bench row boundary)."""
-        self._emit({"type": "mark", "name": name, **fields})
-
     def close(self) -> None:
         if self._f.closed:
             return
@@ -95,12 +91,11 @@ class ManifestWriter:
 
 
 def read_manifest(path: str) -> Dict[str, Any]:
-    """Parse a manifest: ``{"run": header, "spans": [...], "marks": [...],
+    """Parse a manifest: ``{"run": header, "spans": [...],
     "end": totals-or-None}``.  Raises on a missing/invalid header so callers
     fail loudly on a file that is not a manifest."""
     run = None
     spans: List[dict] = []
-    marks: List[dict] = []
     end = None
     with open(path) as f:
         for line in f:
@@ -117,10 +112,8 @@ def read_manifest(path: str) -> Dict[str, Any]:
                 run = obj
             elif t == "span":
                 spans.append(obj)
-            elif t == "mark":
-                marks.append(obj)
             elif t == "end":
                 end = obj
     if run is None:
         raise ValueError(f"{path}: no run header — not an obs manifest")
-    return {"run": run, "spans": spans, "marks": marks, "end": end}
+    return {"run": run, "spans": spans, "end": end}

@@ -12,19 +12,25 @@ or, as a decorator around a whole stage::
     @span.wrap("cluster")
     def cluster_clients(...): ...
 
-Spans nest (each records its depth and parent index), carry arbitrary
-scalar attributes, and snapshot the JAX counters (``obs.counters``) at both
-boundaries so every event knows how many jit compilations and
-``jax.device_get`` transfers happened inside it — including everything its
-children did; readers that want exclusive time subtract child durations
-(``tools/trace_report.py`` does).
+Spans nest (each records its depth; readers rebuild parents from the close
+order), carry arbitrary scalar attributes, and snapshot the JAX counters
+(``obs.counters``) at both boundaries so every event knows how many jit
+compilations and ``jax.device_get`` transfers happened inside it —
+including everything its children did; readers that want exclusive time
+subtract child durations (``tools/trace_report.py`` does).
+
+Profiler sink: every span, whether or not the tracer is enabled, also
+enters a ``jax.profiler.TraceAnnotation`` under its bare name (attrs left
+out, so one phase's annotations group).  Any ``jax.profiler`` trace then
+holds the program's phases on its host plane, on the device's clock: a
+device idle gap is attributed to the innermost phase covering it.  The
+profiler's own TraceMe check decides whether the annotation is recorded.
 
 Cost model: when tracing is disabled (the default) ``span(...)`` allocates
-one small handle whose ``__enter__``/``__exit__`` are a single flag check —
-nothing else runs, no clock is read, no event is stored.  Spans sit at
-phase granularity (a handful per orchestrator segment, never inside a
-``lax.scan``), so the disabled overhead on a benchmark row is far below
-measurement noise (<1%, asserted by the bench-smoke acceptance run).
+one small handle whose ``__enter__``/``__exit__`` enter and leave the
+annotation and check one flag — no clock is read, no event is stored.
+Spans sit at phase granularity (a handful per orchestrator segment, never
+inside a ``lax.scan``), so the disabled cost per run is microseconds.
 
 Timing semantics under JAX's async dispatch: a span measures *host*
 wall-clock between its boundaries.  Phases that only enqueue device work
@@ -44,6 +50,8 @@ import functools
 import time
 from typing import Any, Dict, List, Optional
 
+from jax.profiler import TraceAnnotation
+
 from repro.obs import counters as _counters
 
 __all__ = ["SpanEvent", "span", "enabled", "start", "stop", "events",
@@ -53,18 +61,17 @@ __all__ = ["SpanEvent", "span", "enabled", "start", "stop", "events",
 class SpanEvent:
     """One closed span: name, wall-clock window, nesting, counter deltas."""
 
-    __slots__ = ("name", "t0", "dur", "depth", "parent", "attrs",
+    __slots__ = ("name", "t0", "dur", "depth", "attrs",
                  "compiles", "transfers", "bytes_fetched",
                  "live_arrays", "live_bytes")
 
-    def __init__(self, name, t0, dur, depth, parent, attrs,
+    def __init__(self, name, t0, dur, depth, attrs,
                  compiles, transfers, bytes_fetched,
                  live_arrays=None, live_bytes=None):
         self.name = name
         self.t0 = t0                    # seconds since tracer start
         self.dur = dur                  # seconds
         self.depth = depth              # 0 = top level
-        self.parent = parent            # index into the event list, or None
         self.attrs = attrs              # scalar labels ({} when none)
         self.compiles = compiles        # jit compilations inside the span
         self.transfers = transfers      # jax.device_get calls inside
@@ -74,7 +81,7 @@ class SpanEvent:
 
     def to_dict(self) -> Dict[str, Any]:
         d = {"type": "span", "name": self.name, "t0": self.t0,
-             "dur": self.dur, "depth": self.depth, "parent": self.parent,
+             "dur": self.dur, "depth": self.depth,
              "compiles": self.compiles, "transfers": self.transfers,
              "bytes_fetched": self.bytes_fetched}
         if self.attrs:
@@ -143,14 +150,17 @@ def drain() -> List[SpanEvent]:
 class _SpanHandle:
     """Context manager for one span; ``span.wrap`` builds the decorator."""
 
-    __slots__ = ("name", "attrs", "_live")
+    __slots__ = ("name", "attrs", "_live", "_annotation")
 
     def __init__(self, name: str, attrs: Optional[dict]):
         self.name = name
         self.attrs = attrs
         self._live = False
+        self._annotation = None
 
     def __enter__(self):
+        self._annotation = TraceAnnotation(self.name)
+        self._annotation.__enter__()
         if not _enabled:
             return self
         self._live = True
@@ -159,6 +169,8 @@ class _SpanHandle:
         return self
 
     def __exit__(self, exc_type, exc, tb):
+        self._annotation.__exit__(exc_type, exc, tb)
+        self._annotation = None
         if not self._live:
             return False
         self._live = False
@@ -172,7 +184,7 @@ class _SpanHandle:
         # event with a smaller depth (see tools/trace_report.py).
         ev = SpanEvent(
             name=name, t0=t0 - _t_start, dur=t1 - t0, depth=depth,
-            parent=None, attrs=attrs or {},
+            attrs=attrs or {},
             compiles=c1[0] - c0[0], transfers=c1[1] - c0[1],
             bytes_fetched=c1[2] - c0[2])
         if _snapshot_memory:
@@ -184,7 +196,8 @@ class _SpanHandle:
 
 
 def span(name: str, **attrs) -> _SpanHandle:
-    """A context manager timing one phase; no-op unless tracing is active.
+    """A context manager timing one phase while tracing is active, and
+    annotating any running ``jax.profiler`` trace with ``name`` always.
 
     Keyword arguments become the event's ``attrs`` (keep them scalar — they
     are written verbatim into the JSONL manifest)."""
@@ -196,8 +209,6 @@ def _wrap(name: str, **attrs):
     def deco(fn):
         @functools.wraps(fn)
         def inner(*args, **kwargs):
-            if not _enabled:          # skip even the handle allocation
-                return fn(*args, **kwargs)
             with span(name, **attrs):
                 return fn(*args, **kwargs)
         return inner

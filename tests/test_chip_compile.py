@@ -11,6 +11,7 @@ samples, 32 PCA features and 3 clusters; the gate scores each client's
 whole stack (R = cap) and 3 reserves of 40 samples per receiver.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -90,6 +91,25 @@ def test_recon_gate_compiles(one_chip, chip_dispatch, shape):
                          _spec(one_chip, shape), _spec(one_chip, shape),
                          _spec(one_chip, shape[:-1]))
     assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("kernel", ["kmeans_assign", "recon_gate"])
+def test_compiled_kernel_carries_its_name(one_chip, chip_dispatch, kernel):
+    """Each kernel's custom-call is named after it (``pallas_call``'s
+    ``name``), so a device trace tells the two apart."""
+    if kernel == "kmeans_assign":
+        text = _compile_text(jax.vmap(ops.kmeans_assign),
+                             _spec(one_chip, (N_CLIENTS, CAP, N_PCA)),
+                             _spec(one_chip, (N_CLIENTS, K, N_PCA)))
+    else:
+        shape = (N_CLIENTS, CAP, PIXELS)
+        text = _compile_text(ops.recon_gate_score, _spec(one_chip, shape),
+                             _spec(one_chip, shape),
+                             _spec(one_chip, shape[:-1]))
+    calls = re.findall(r"%([\w.-]+) = [^\n]* custom-call\([^\n]*"
+                       r"tpu_custom_call", text)
+    assert calls and all(re.fullmatch(kernel + r"(\.\d+)?", c)
+                         for c in calls), calls
 
 
 def test_clustering_program_contains_kernel(one_chip, chip_dispatch):

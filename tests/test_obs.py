@@ -53,6 +53,33 @@ def test_span_nesting_close_order_and_attrs():
         evs[1].dur + evs[2].dur)             # top-level spans only
 
 
+@pytest.mark.parametrize("tracing", [False, True],
+                         ids=["tracer-off", "tracer-on"])
+def test_span_lands_in_profiler_trace(tmp_path, tracing):
+    """A span enters a ``jax.profiler`` annotation under its bare name
+    (attrs left out), on the host plane and nested as the spans are,
+    whether or not the obs tracer records it."""
+    if tracing:
+        obs.enable()
+    before = [e.name for e in obs.events()]
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with obs.span("outer-phase", segment=3):
+            with obs.span("inner-phase"):
+                jnp.arange(4.0).block_until_ready()
+    finally:
+        jax.profiler.stop_trace()
+    (pb,) = tmp_path.rglob("*.xplane.pb")
+    data = jax.profiler.ProfileData.from_file(str(pb))
+    host = {e.name: (e.start_ns, e.end_ns)
+            for p in data.planes if p.name == "/host:CPU"
+            for line in p.lines for e in line.events}
+    outer, inner = host["outer-phase"], host["inner-phase"]
+    assert outer[0] <= inner[0] <= inner[1] <= outer[1]
+    assert [e.name for e in obs.events()] == before + (
+        ["inner-phase", "outer-phase"] if tracing else [])
+
+
 def test_counters_attribute_compiles_and_transfers():
     obs.enable()
 
@@ -87,7 +114,6 @@ def test_manifest_round_trip_sums_to_run_total(tmp_path):
             pass
     with obs.span("c"):
         pass
-    obs.mark("row", row="0")
     rec = obs.disable()
 
     man = obs.read_manifest(path)
@@ -95,7 +121,8 @@ def test_manifest_round_trip_sums_to_run_total(tmp_path):
     assert man["run"]["meta"] == {"test": "round-trip"}
     assert man["run"]["jax_version"] == jax.__version__
     assert len(man["spans"]) == 4
-    assert man["marks"] == [{"type": "mark", "name": "row", "row": "0"}]
+    assert set(man) == {"run", "spans", "end"}
+    assert not any("parent" in s for s in man["spans"])
     assert man["end"] is not None
 
     # tree reconstruction: b,b close under a; c is top level

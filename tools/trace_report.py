@@ -133,8 +133,6 @@ def report(path: str, top: Optional[int] = None) -> str:
     else:
         lines.append(f"span envelope: {wall:.3f} s (no end line — "
                      "run did not finalise)")
-    for m in man["marks"]:
-        lines.append("mark: " + json.dumps(m, sort_keys=True))
     return "\n".join(lines)
 
 
