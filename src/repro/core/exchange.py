@@ -184,9 +184,9 @@ def _pretrain_step(p, x, m, ae_cfg, lr, rules):
     p = sh.constrain_clients(p, rules)
     x = sh.constrain_clients(x, rules)
     m = sh.constrain_clients(m, rules)
-    g = jax.vmap(
+    g = sh.client_map(jax.vmap(
         lambda pp, xx, mm: jax.grad(ae.masked_recon_loss)(pp, xx, mm, ae_cfg)
-    )(p, x, m)
+    ), rules)(p, x, m)
     new = jax.tree.map(lambda pp, gg: pp - lr * gg, p, g)
     return sh.constrain_clients(new, rules)
 
@@ -395,7 +395,8 @@ def _gate_scores(params, own, own_mask, cand, cand_mask, allowed, fail_u,
     n, max_n = own.shape[:2]
     k, r = cand.shape[1:3]
 
-    recon = jax.vmap(lambda p, x: ae.reconstruct(p, x, ae_cfg))
+    recon = sh.client_map(
+        jax.vmap(lambda p, x: ae.reconstruct(p, x, ae_cfg)), rules)
     gate_score = sh.client_map(ops.recon_gate_score, rules)
     y_own = recon(params, own)
     base = gate_score(y_own.reshape(n, max_n, -1), own.reshape(n, max_n, -1),

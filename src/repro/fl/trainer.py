@@ -136,14 +136,12 @@ def _round_body(cfg: FLConfig, ae_cfg, carry, data, sizes, agg_mask,
                                             agg_mask))
     gp = rep(gp)
 
-    def local_grad(params_i, data_i, size_i, key_i, gparams):
+    def local_grad(params_i, data_i, size_i, key_i):
         idx = jax.random.randint(key_i, (cfg.batch_size,), 0, size_i)
-        x = data_i[idx]
-        g = loss_grad(params_i, x, ae_cfg)
-        if cfg.scheme == "fedprox":   # prox pull toward the global model
-            g = jax.tree.map(lambda gg, p, gp: gg + cfg.prox_mu * (p - gp),
-                             g, params_i, gparams)
-        return g
+        return loss_grad(params_i, data_i[idx], ae_cfg)
+
+    # shard-local: each device convolves only its own clients' filters
+    local_grads = sh.client_map(jax.vmap(local_grad), rules)
 
     def apply_update(cp, grads, mu, nu, t):
         if cfg.local_opt == "sgd":    # Eq. 8, paper-faithful
@@ -163,8 +161,11 @@ def _round_body(cfg: FLConfig, ae_cfg, carry, data, sizes, agg_mask,
         cp, mu, nu, t = state
         t = t + 1.0
         keys = cl(jax.random.split(key_t, n))
-        grads = jax.vmap(local_grad, in_axes=(0, 0, 0, 0, None))(
-            cp, data, sizes, keys, gp)
+        grads = local_grads(cp, data, sizes, keys)
+        if cfg.scheme == "fedprox":   # prox pull toward the global model
+            grads = jax.tree.map(
+                lambda g, p, q: g + cfg.prox_mu * (p - q[None]),
+                grads, cp, gp)
         if cfg.scheme == "fedsgd":
             # aggregate gradients every iteration; all clients share
             # the global model (stragglers' grads are dropped)

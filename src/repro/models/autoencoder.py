@@ -3,6 +3,12 @@
 Matches the paper's setup (Sec. V): a small CNN AE per client trained on
 reconstruction MSE; the encoder embedding feeds the linear-evaluation probe.
 Works for FMNIST-like (28x28x1) and CIFAR-like (32x32x3) inputs (NHWC).
+
+The layers are XLA convolutions on every backend.  The client paths (FL
+trainer, AE pretrain, exchange gate) vmap them over stacked per-client
+filters, which XLA lowers to one convolution over all clients; a program
+sharded over the client axis maps those calls with ``sharding.client_map``
+so that each device convolves only its own clients.
 """
 from __future__ import annotations
 
@@ -59,55 +65,20 @@ def init_ae(key, cfg: AEConfig, dtype=jnp.float32):
     return cm.init_params(key, ae_specs(cfg), dtype)
 
 
-def _same_pads(size, k, s):
-    out = -(-size // s)
-    pad = max((out - 1) * s + k - size, 0)
-    return pad // 2, pad - pad // 2
-
-
-def _patch_conv(xp, w, stride):
-    """Stride through pre-padded xp, gather the kh*kw shifted views, contract
-    with one einsum: (B,ho,wo,kh*kw*? ...) x (kh*kw,C,F) on the GEMM path."""
-    kh, kw, c, f = w.shape
-    ho = (xp.shape[1] - kh) // stride + 1
-    wo = (xp.shape[2] - kw) // stride + 1
-    patches = jnp.stack(
-        [xp[:, dy:dy + (ho - 1) * stride + 1:stride,
-             dx:dx + (wo - 1) * stride + 1:stride]
-         for dy in range(kh) for dx in range(kw)], axis=3)
-    return jnp.einsum("bhwpc,pcf->bhwf", patches, w.reshape(kh * kw, c, f),
-                      preferred_element_type=jnp.float32)
+_DN = ("NHWC", "HWIO", "NHWC")
 
 
 def _conv(x, w, b, stride=1):
-    """'SAME' conv via patch-gather + einsum (matches lax.conv numerics).
-
-    The batched client paths (FL trainer, exchange gate engine) vmap this
-    over stacked per-client filters; XLA:CPU lowers a vmapped-filter conv to
-    a slow grouped-conv loop, while the einsum stays one fast batched GEMM
-    (and feeds the MXU directly on TPU)."""
-    kh, kw = w.shape[:2]
-    plo, phi = _same_pads(x.shape[1], kh, stride)
-    qlo, qhi = _same_pads(x.shape[2], kw, stride)
-    xp = jnp.pad(x, ((0, 0), (plo, phi), (qlo, qhi), (0, 0)))
-    return _patch_conv(xp, w, stride) + b
+    """'SAME' conv, NHWC activations and HWIO filters."""
+    return jax.lax.conv_general_dilated(
+        x, w, (stride, stride), "SAME", dimension_numbers=_DN) + b
 
 
 def _conv_t(x, w, b, stride=2):
-    """'SAME' transposed conv: zero-stuff + stride-1 patch conv.
-
-    Same padding rule as jax.lax.conv_transpose; avoids XLA:CPU's slow
-    lhs-dilated conv path on top of the grouped-conv issue above."""
-    bsz, h, wd, c = x.shape
-    kh, kw = w.shape[:2]
-    xd = jnp.zeros(
-        (bsz, stride * h - (stride - 1), stride * wd - (stride - 1), c),
-        x.dtype).at[:, ::stride, ::stride].set(x)
-    pad_len = kh + stride - 2
-    pad_a = kh - 1 if stride > kh - 1 else -(-pad_len // 2)
-    xp = jnp.pad(xd, ((0, 0), (pad_a, pad_len - pad_a),
-                      (pad_a, pad_len - pad_a), (0, 0)))
-    return _patch_conv(xp, w, 1) + b
+    """'SAME' transposed conv: ``lax.conv_transpose``'s padding rule, no
+    kernel flip."""
+    return jax.lax.conv_transpose(
+        x, w, (stride, stride), "SAME", dimension_numbers=_DN) + b
 
 
 def encode(params, x, cfg: AEConfig):
